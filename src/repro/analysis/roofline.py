@@ -12,7 +12,9 @@ HLO (collectives only exist after SPMD partitioning) and summing operand
 sizes of all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute ops.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+The terms are estimates for a target chip, never measurements: the
+dry-run compiles on the host, so its peaks come from ``CHIPS`` under
+``TARGET_KIND``, not from the device that runs the compile.
 """
 from __future__ import annotations
 
@@ -20,9 +22,38 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    """Published per-chip peaks."""
+    peak_bf16_flops: float       # FLOP/s
+    hbm_bytes: float             # HBM capacity
+    hbm_bw: float                # bytes/s
+    ici_bw: float                # bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# interconnect over 4 links (50 GB/s each).
+CHIPS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec(peak_bf16_flops=197e12, hbm_bytes=16e9,
+                            hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peaks of ``device_kind``; a kind not in ``CHIPS`` is an error."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(CHIPS)}") from None
+
+
+TARGET_KIND = "TPU v5 lite"  # the chip the dry-run estimates model
+PEAK_FLOPS = CHIPS[TARGET_KIND].peak_bf16_flops
+HBM_BW = CHIPS[TARGET_KIND].hbm_bw
+ICI_BW = CHIPS[TARGET_KIND].ici_bw
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -34,6 +35,10 @@ from repro.optim import optimizers
 # trials likewise all start from one fixed default). PipeTune's probing
 # discovers when the aggressive configs fit and are faster.
 SYS_DEFAULT = {"remat": "block", "microbatches": 4, "precision": "fp32"}
+
+# The power model's utilization input for real epochs. Nothing measures it
+# yet, so RealBackend energies are estimates, not measurements.
+ASSUMED_UTILIZATION = 0.5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,15 +97,25 @@ class TrialState:
     eval_batch: dict
     seed: int
     loss_last: float = float("nan")
+    device: Any = None     # the device holding this trial's arrays
 
 
 class RealBackend:
-    """Trains repro.models.small workloads for real (paper Table 3)."""
+    """Trains repro.models.small workloads for real (paper Table 3).
+
+    Each trial's params, optimizer state and batches live on one device,
+    chosen round-robin over the first ``n_devices`` local devices (all of
+    them when None) when the trial starts, so concurrent trials
+    (``--parallelism N``) spread over a host's chips.
+    """
 
     def __init__(self, n_train: int = 2048, n_eval: int = 512,
-                 steps_per_epoch: Optional[int] = 8, compile_workers: int = 2):
+                 steps_per_epoch: Optional[int] = 8, compile_workers: int = 2,
+                 n_devices: Optional[int] = None):
         self.n_train, self.n_eval = n_train, n_eval
         self.steps_per_epoch = steps_per_epoch
+        self._devices = jax.local_devices()[:n_devices]
+        self._device_turn = itertools.count()
         self._step_cache: Dict[tuple, Any] = {}
         self._compile_pool = cf.ThreadPoolExecutor(max_workers=compile_workers)
         self._pending: Dict[tuple, cf.Future] = {}
@@ -146,13 +161,16 @@ class RealBackend:
         bs = int(hparams.get("batch_size", 64))
         bs = min(bs, len(next(iter(train.values()))))
         data = synthetic.Batches(train, bs, seed=seed)
-        params = small.init(jax.random.PRNGKey(seed), cfg)
-        opt = self._opt(hparams)
+        device = self._devices[next(self._device_turn) % len(self._devices)]
+        params = jax.device_put(small.init(jax.random.PRNGKey(seed), cfg),
+                                device)
+        opt_state = jax.device_put(self._opt(hparams).init(params), device)
         return TrialState(workload=workload, hparams=dict(hparams), cfg=cfg,
-                          params=params, opt_state=opt.init(params), step=0,
+                          params=params, opt_state=opt_state, step=0,
                           epoch=0, data=data,
-                          eval_batch={k: v[:256] for k, v in test.items()},
-                          seed=seed)
+                          eval_batch=jax.device_put(
+                              {k: v[:256] for k, v in test.items()}, device),
+                          seed=seed, device=device)
 
     def _opt(self, hparams):
         lr = float(hparams.get("learning_rate", 0.01))
@@ -280,7 +298,8 @@ class RealBackend:
         for batch in ts.data.epoch(ts.epoch):
             if self.steps_per_epoch and n_steps >= self.steps_per_epoch:
                 break
-            b = {k: jnp.asarray(v[:bs]) for k, v in batch.items()}
+            b = jax.device_put({k: v[:bs] for k, v in batch.items()},
+                               ts.device)
             t0 = time.time()
             params, opt_state, l, a = train_step(
                 params, opt_state, jnp.int32(ts.step), b,
@@ -301,9 +320,10 @@ class RealBackend:
             if step_times[0] > 3.0 * med:
                 compile_s += step_times[0] - med
                 step_times[0] = med
-        acc = float(eval_step(params, {k: jnp.asarray(v) for k, v in
-                                       ts.eval_batch.items()}))
-        util = 0.5          # CPU proxy; refined by profile on TPU
+        acc = float(eval_step(params, ts.eval_batch))
+        # not measured: energy comes from the power model at this assumed
+        # utilization, so it is an estimate proportional to step time
+        util = ASSUMED_UTILIZATION
         e = energy_lib.epoch_energy(step_times, util, chips=1)
         profile = None
         if collect_profile:

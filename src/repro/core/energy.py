@@ -31,8 +31,7 @@ def trapezoidal_energy(power_samples: Sequence[float],
     p = np.asarray(power_samples, np.float64)
     if p.size < 2:
         return float(p.sum() * dt_s)
-    trap = getattr(np, 'trapezoid', getattr(np, 'trapz', None))
-    return float(trap(p, dx=dt_s))
+    return float(np.trapezoid(p, dx=dt_s))
 
 
 def epoch_energy(step_times: Sequence[float], utilization: float,

@@ -83,6 +83,12 @@ class JobResult:
     def best_train_time(self):
         return self.best_record.train_time if self.best_record else 0.0
 
+    @property
+    def compile_s(self) -> float:
+        """Seconds spent compiling step functions, over every epoch."""
+        return sum(e.compile_s for r in self.records.values()
+                   for e in r.epochs)
+
 
 class TrialRunner:
     """Executes trials for a scheduler; caches trial state for rung resume."""

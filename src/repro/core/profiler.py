@@ -110,9 +110,8 @@ class Profiler:
             ev["mem.temp_bytes"] = memory.get("temp_size_in_bytes", 0)
             ev["mem.out_bytes"] = memory.get("output_size_in_bytes", 0)
             ev["mem.code_bytes"] = memory.get("generated_code_size_in_bytes", 0)
-            hbm = 16 * 2**30
-            ev["mem.peak_frac"] = (ev["mem.args_bytes"]
-                                   + ev["mem.temp_bytes"]) / hbm
+            # mem.peak_frac stays 0: no device capacity is known here,
+            # and assuming one would mislabel every other chip
             ev["mem.params_bytes"] = memory.get("params_bytes", 0)
             ev["mem.opt_bytes"] = memory.get("opt_bytes", 0)
             ev["mem.acts_bytes"] = memory.get("acts_bytes", 0)

@@ -23,11 +23,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+LANES = 128        # TPU vector lanes: width of the per-row m / l / lse tiles
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                 *, scale: float, causal: bool, window: Optional[int],
-                 q_block: int, kv_block: int, kv_len: int):
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs, scale: float,
+                 causal: bool, window: Optional[int], q_block: int,
+                 kv_block: int, kv_len: int):
+    # refs = ([lse_ref,] acc_ref, m_ref, l_ref); m and l are kept
+    # replicated over LANES lanes so every per-row vector is a legal tile
+    lse_ref = refs[0] if len(refs) == 4 else None
+    acc_ref, m_ref, l_ref = refs[-3:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -66,20 +71,21 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         if window is not None:
             ok = jnp.logical_and(ok, k_pos > q_pos - window)
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                              # (Bq, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr[:, :1] + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
@@ -125,7 +131,16 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
         _attn_kernel, scale=scale, causal=causal, window=window,
         q_block=q_block, kv_block=kv_block, kv_len=T)
 
-    out, lse = pl.pallas_call(
+    o_spec = pl.BlockSpec((1, q_block, D), lambda i, qi, ki: (i, qi, 0))
+    o_shape = jax.ShapeDtypeStruct((B * K * G, Sp, D), q.dtype)
+    if return_lse:
+        out_specs = [o_spec, pl.BlockSpec((1, q_block, LANES),
+                                          lambda i, qi, ki: (i, qi, 0))]
+        out_shape = [o_shape, jax.ShapeDtypeStruct((B * K * G, Sp, LANES),
+                                                   jnp.float32)]
+    else:
+        out_specs, out_shape = o_spec, o_shape
+    res = pl.pallas_call(
         kernel,
         grid=(B * K * G, nq, nk),
         in_specs=[
@@ -133,21 +148,18 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
             pl.BlockSpec((1, kv_block, D), lambda i, qi, ki: (i // G, ki, 0)),
             pl.BlockSpec((1, kv_block, D), lambda i, qi, ki: (i // G, ki, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, q_block, D), lambda i, qi, ki: (i, qi, 0)),
-            pl.BlockSpec((1, q_block), lambda i, qi, ki: (i, qi)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B * K * G, Sp, D), q.dtype),
-                   jax.ShapeDtypeStruct((B * K * G, Sp), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((q_block, D), jnp.float32),   # acc
-            pltpu.VMEM((q_block,), jnp.float32),     # running max m
-            pltpu.VMEM((q_block,), jnp.float32),     # running sum l
+            pltpu.VMEM((q_block, D), jnp.float32),       # acc
+            pltpu.VMEM((q_block, LANES), jnp.float32),   # running max m
+            pltpu.VMEM((q_block, LANES), jnp.float32),   # running sum l
         ],
         interpret=interpret,
     )(q2, k2, v2)
+    out = res[0] if return_lse else res
     out = jnp.moveaxis(out[:, :S].reshape(B, K, G, S, D), 3, 1)
     if return_lse:
-        lse = jnp.moveaxis(lse[:, :S].reshape(B, K, G, S), 3, 1)
+        lse = jnp.moveaxis(res[1][:, :S, 0].reshape(B, K, G, S), 3, 1)
         return out, lse
     return out

@@ -64,16 +64,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0][:, None]                        # (Bq, 1)
+        delta = delta_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         ok = _mask(q_start, k_start, q_block, kv_block, kv_len, causal, window)
         s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                       # (Bq, Bk)
+        p = jnp.exp(s - lse)                                # (Bq, Bk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         acc_ref[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                     preferred_element_type=jnp.float32)
 
@@ -111,19 +111,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0][:, None]                        # (Bq, 1)
+        delta = delta_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         ok = _mask(q_start, k_start, q_block, kv_block, kv_len, causal, window)
         s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (Bk, D)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (Bk, D)
@@ -172,8 +172,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         return x2
 
     q2, do2, o2 = flat_q(q, (D,)), flat_q(do, (D,)), flat_q(out, (D,))
-    lse2 = flat_q(lse[..., None], (1,))[..., 0]
-    dl2 = flat_q(delta[..., None], (1,))[..., 0]
+    # per-row vectors travel as (BKG, 1, Sp) rows, so a block (1, 1, Bq)
+    # satisfies the TPU's (8, 128) tiling rule
+    lse2 = flat_q(lse, ())[:, None]
+    dl2 = flat_q(delta, ())[:, None]
     k2 = jnp.moveaxis(k, 1, 2).reshape(B * K, T, D)
     v2 = jnp.moveaxis(v, 1, 2).reshape(B * K, T, D)
     if Tp != T:
@@ -190,8 +192,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
             pl.BlockSpec((1, kv_block, D), lambda i, qi, ki: (i // G, ki, 0)),
             pl.BlockSpec((1, kv_block, D), lambda i, qi, ki: (i // G, ki, 0)),
             pl.BlockSpec((1, q_block, D), lambda i, qi, ki: (i, qi, 0)),
-            pl.BlockSpec((1, q_block), lambda i, qi, ki: (i, qi)),
-            pl.BlockSpec((1, q_block), lambda i, qi, ki: (i, qi)),
+            pl.BlockSpec((1, 1, q_block), lambda i, qi, ki: (i, 0, qi)),
+            pl.BlockSpec((1, 1, q_block), lambda i, qi, ki: (i, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, q_block, D), lambda i, qi, ki: (i, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * K * G, Sp, D), q.dtype),
@@ -205,6 +207,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
     def qmap(i, ki, qg):
         return (i * G + qg % G, qg // G, 0)
 
+    def rowmap(i, ki, qg):
+        return (i * G + qg % G, 0, qg // G)
+
     dk2, dv2 = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           window=window, q_block=q_block, kv_block=kv_block,
@@ -215,8 +220,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
             pl.BlockSpec((1, kv_block, D), lambda i, ki, qg: (i, ki, 0)),
             pl.BlockSpec((1, kv_block, D), lambda i, ki, qg: (i, ki, 0)),
             pl.BlockSpec((1, q_block, D), qmap),
-            pl.BlockSpec((1, q_block), lambda i, ki, qg: qmap(i, ki, qg)[:2]),
-            pl.BlockSpec((1, q_block), lambda i, ki, qg: qmap(i, ki, qg)[:2]),
+            pl.BlockSpec((1, 1, q_block), rowmap),
+            pl.BlockSpec((1, 1, q_block), rowmap),
         ],
         out_specs=[
             pl.BlockSpec((1, kv_block, D), lambda i, ki, qg: (i, ki, 0)),

@@ -2,14 +2,16 @@
 
 Grid: (B, num_R_blocks, num_S_chunks) with the sequence axis minor-most —
 the TPU core walks chunks sequentially carrying the hidden state h in VMEM
-scratch. Inside a chunk, a fori_loop applies h = exp(log_a)*h + b per step on
-the VPU (pure elementwise on an (Rb,) vector — the recurrence has no matmul,
-so the kernel's job is purely to keep h and the chunk tiles resident in VMEM
-and stream (log_a, b) through one DMA per chunk).
+scratch. Inside a chunk, a fori_loop walks 8-row tiles (one f32 sublane
+tile): each tile of (log_a, b) is loaded once, the 8 steps of
+h = exp(log_a)*h + b run on the VPU against static rows of the tile, and
+the 8 hidden states are stored back as one tile. The recurrence has no
+matmul, so the kernel's job is purely to keep h and the chunk tiles
+resident in VMEM and stream (log_a, b) through one DMA per chunk.
 
 This is the TPU adaptation of Griffin's fused scan: the GPU version leans on
 warp shuffles for the intra-warp scan; on TPU the sequential-grid + VMEM
-carry is the idiomatic equivalent (DESIGN.md §5).
+carry is the idiomatic equivalent.
 """
 from __future__ import annotations
 
@@ -20,26 +22,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+ROWS = 8          # f32 sublane tile: rows handled per loop iteration
+
 
 def _rglru_kernel(la_ref, b_ref, h0_ref, o_ref, h_ref, *, chunk: int):
     si = pl.program_id(2)
 
     @pl.when(si == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)          # (1, Rb)
 
-    la = la_ref[0].astype(jnp.float32)        # (chunk, Rb)
-    b = b_ref[0].astype(jnp.float32)
-
-    def step(t, h):
-        h = jnp.exp(la[t]) * h + b[t]
-        # dslice(0, 1) rather than a bare int: interpret-mode state
-        # discharge chokes on int indices mixed with dynamic slices
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[None, None].astype(o_ref.dtype))
+    def tile(i, h):
+        rows = pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
+        a = jnp.exp(la_ref[0, rows, :].astype(jnp.float32))  # (ROWS, Rb)
+        b = b_ref[0, rows, :].astype(jnp.float32)
+        hs = []
+        for r in range(ROWS):
+            h = a[r:r + 1] * h + b[r:r + 1]
+            hs.append(h)
+        o_ref[0, rows, :] = jnp.concatenate(hs, axis=0).astype(o_ref.dtype)
         return h
 
-    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    h_ref[...] = jax.lax.fori_loop(0, chunk // ROWS, tile, h_ref[...])
 
 
 def rglru_scan(log_a, b, h0=None, *, chunk=None, r_block=None,
@@ -47,7 +51,8 @@ def rglru_scan(log_a, b, h0=None, *, chunk=None, r_block=None,
     """log_a, b: (B, S, R) fp32; h0: (B, R) fp32. Returns (h, h_last).
 
     None defaults resolve via the kernel find-db / platform auto-detect
-    (``repro.kernels.findb``); explicit arguments always win.
+    (``repro.kernels.findb``); explicit arguments always win. ``chunk`` is
+    rounded up to a multiple of 8 rows.
     """
     from repro.kernels import findb
     B, S, R = log_a.shape
@@ -60,7 +65,7 @@ def rglru_scan(log_a, b, h0=None, *, chunk=None, r_block=None,
         r_block = tuned["r_block"] if r_block is None else r_block
     if h0 is None:
         h0 = jnp.zeros((B, R), jnp.float32)
-    chunk = min(chunk, S)
+    chunk = -(-min(chunk, S) // ROWS) * ROWS
     r_block = min(r_block, R)
     ns = -(-S // chunk)
     nr = -(-R // r_block)
@@ -76,13 +81,13 @@ def rglru_scan(log_a, b, h0=None, *, chunk=None, r_block=None,
         in_specs=[
             pl.BlockSpec((1, chunk, r_block), lambda bi, ri, si: (bi, si, ri)),
             pl.BlockSpec((1, chunk, r_block), lambda bi, ri, si: (bi, si, ri)),
-            pl.BlockSpec((1, r_block), lambda bi, ri, si: (bi, ri)),
+            pl.BlockSpec((1, 1, r_block), lambda bi, ri, si: (bi, 0, ri)),
         ],
         out_specs=pl.BlockSpec((1, chunk, r_block),
                                lambda bi, ri, si: (bi, si, ri)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, Rp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((r_block,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, r_block), jnp.float32)],
         interpret=interpret,
-    )(log_a, b, h0)
+    )(log_a, b, h0[:, None, :])
     h = out[:, :S, :R]
     return h, h[:, -1]

@@ -108,8 +108,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):        # jax returns [dict] on some
-        cost = cost[0] if cost else {}         # versions, dict on others
     hlo = compiled.as_text()
     hcost = hlo_analysis.analyze(hlo)       # loop-aware per-device cost
     chips = _mesh_chips(mesh)

@@ -1,8 +1,10 @@
 """Training launcher: real training of a (reduced or custom) arch on local
 devices, with checkpoint/restart and optional PipeTune system tuning.
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
+    PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
         --steps 100 --ckpt /tmp/ck
+
+``--no-reduced`` trains the published widths instead of the smoke config.
 """
 from __future__ import annotations
 
@@ -17,16 +19,20 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import synthetic
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.sysargs import (add_kernel_db_arg, add_system_args,
                                   install_kernel_db_from_args,
                                   system_config_from_args)
 from repro.optim import optimizers
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the family-preserving smoke config "
+                         "(default); --no-reduced trains the published one")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -36,8 +42,15 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> dict:
+    """Train; returns ``{"losses", "step_times"}`` for the steps run, each
+    step timed on the host clock until its loss is back on the host (the
+    first step's time includes compiling)."""
+    args = parse_args(argv)
+    enable_compile_cache()
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get_config(args.arch)
     if steps_lib.is_encdec(cfg):
@@ -61,23 +74,26 @@ def main():
     toks = synthetic.make_lm_dataset(0, args.batch * args.seq * 32, cfg.vocab)
     stream = toks[:args.batch * args.seq * 32].reshape(-1, args.batch,
                                                        args.seq)
-    t0 = time.time()
+    losses, step_times = [], []
     loss = float("nan")
     for step in range(start, args.steps):
         chunk = stream[step % len(stream)]
         batch = {"tokens": jnp.asarray(chunk),
                  "labels": jnp.asarray(np.roll(chunk, -1, -1))}
+        t0 = time.perf_counter()
         state, m = step_fn(state, batch)
         loss = float(m["loss"])
+        step_times.append(time.perf_counter() - t0)
+        losses.append(loss)
         if mgr and (step + 1) % args.ckpt_every == 0:
             mgr.save(step + 1, state, metadata={"step": step + 1})
-        if (step + 1) % 10 == 0:
+        if step == start or (step + 1) % 10 == 0:
             print(f"step {step+1:4d} loss={loss:.4f} "
-                  f"({(time.time()-t0)/10:.2f}s/step)")
-            t0 = time.time()
+                  f"({step_times[-1]:.3f}s this step)")
     if mgr:
         mgr.wait()
     print(f"done: final loss {loss:.4f}")
+    return {"losses": losses, "step_times": step_times}
 
 
 if __name__ == "__main__":

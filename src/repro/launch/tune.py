@@ -17,13 +17,14 @@ from repro.api import (Experiment, available_backends, available_executors,
                        available_schedulers, available_tuners)
 from repro.core import SearchSpace
 from repro.core.job import HPTJob, Param
+from repro.launch.cache import enable_compile_cache
 from repro.launch.sysargs import (add_executor_args, add_kernel_db_arg,
                                   add_store_args, executor_from_args,
                                   install_kernel_db_from_args,
                                   store_client_from_args)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="lenet-mnist")
     ap.add_argument("--system", default="pipetune",
@@ -40,8 +41,9 @@ def main():
     ap.add_argument("--plugin", action="append", default=[],
                     help="module to import for register_* side effects")
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     for mod in args.plugin:
         importlib.import_module(mod)
     install_kernel_db_from_args(args)
@@ -53,7 +55,10 @@ def main():
     ])
     job = HPTJob(workload=args.workload, space=space, max_epochs=args.epochs)
 
-    backend_kw = {"n_train": 1024, "n_eval": 256, "steps_per_epoch": 8} \
+    # real trials spread round-robin over as many local devices as run
+    # concurrently: --parallelism 4 on a four-chip host uses every chip
+    backend_kw = {"n_train": 1024, "n_eval": 256, "steps_per_epoch": 8,
+                  "n_devices": args.parallelism} \
         if args.backend == "real" else {}
     tuner_kw = {"max_probes": 4} if args.system == "pipetune" else {}
     sched_kw = {"n_trials": 6} if args.scheduler == "random" else {}
@@ -83,7 +88,9 @@ def main():
           f"({len(res.records)} trials)")
     if res.sim_time_s:
         print(f"  cluster makespan: {res.sim_time_s:.1f}s simulated")
-    print(f"  energy        : {res.energy_j/1e3:.1f} kJ")
+    print(f"  compile time  : {res.compile_s:.1f}s")
+    print(f"  energy        : {res.energy_j/1e3:.1f} kJ "
+          "(power-model estimate, not measured)")
     if args.system == "pipetune":
         print(f"  ground truth  : {res.gt_hits} hits / {res.gt_misses} misses")
     if args.out:
@@ -92,6 +99,7 @@ def main():
                        "hparams": res.best_hparams,
                        "tuning_time_s": res.tuning_time_s,
                        "energy_j": res.energy_j}, f, indent=1)
+    return res
 
 
 if __name__ == "__main__":

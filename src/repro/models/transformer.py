@@ -203,13 +203,13 @@ def _apply_attn_block(p, x, cfg: ModelConfig, sys: SystemConfig, window=None,
     v = layers.shard_heads(v, sys.shard_attn)
     if sys.use_pallas:
         # TPU runtime path: the flash kernel keeps score blocks in VMEM.
-        # (interpret=True on CPU — same math, used by tests; the dry-run
-        # keeps the jnp path, whose score traffic the roofline's kernelized
-        # memory term subtracts.)
+        # (interpret resolves through findb.default_interpret(): compiled
+        # on TPU, interpreted elsewhere; the dry-run keeps the jnp path,
+        # whose score traffic the roofline's kernelized memory term
+        # subtracts.)
         from repro.kernels import ops as kernel_ops
         out = kernel_ops.flash_attention(
-            q, k, v, True, acfg.window, sys.q_chunk, sys.kv_chunk,
-            jax.default_backend() != "tpu")
+            q, k, v, True, acfg.window, sys.q_chunk, sys.kv_chunk)
     elif S > 2048:
         out = layers.chunked_attention(q, k, v, causal=True, window=acfg.window,
                                        q_chunk=sys.q_chunk, kv_chunk=sys.kv_chunk)

@@ -301,6 +301,8 @@ def main(argv=None):
                     help="module to import for register_* side effects")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     for mod in args.plugin:
         importlib.import_module(mod)
 

@@ -7,7 +7,7 @@ import pytest
 
 from repro import configs
 from repro.analysis import hlo_analysis, roofline
-from repro.launch import steps
+from repro.launch import mesh as mesh_lib, steps
 from repro.models.transformer import SystemConfig
 from repro.optim import optimizers
 
@@ -17,7 +17,7 @@ from repro.optim import optimizers
                                   "whisper-small"])
 def test_lower_compile_train_reduced(arch):
     cfg = configs.get_reduced(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.single_device_mesh()
     sys = SystemConfig(microbatches=2, remat="block", batch_axes=("data",))
     opt = optimizers.adamw(1e-3)
     with mesh:
