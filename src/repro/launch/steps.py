@@ -71,10 +71,12 @@ def default_sys(cfg, shape: ShapeSpec, *, dp=16, tp=16, pods=1) -> SystemConfig:
 # steps
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("grad_accum")
 def _tree_add(a, b):
     return jax.tree.map(jnp.add, a, b)
 
 
+@jax.named_scope("grad_accum")
 def _tree_scale(a, s):
     return jax.tree.map(lambda x: x * s, a)
 
@@ -106,7 +108,8 @@ def make_train_step(cfg, sys: SystemConfig, opt: optimizers.Optimizer,
                     y = lax.with_sharding_constraint(
                         y, P(*([None, baxes] + [None] * (y.ndim - 2))))
                 return y
-            mbs = jax.tree.map(resh, batch)
+            with jax.named_scope("grad_accum"):
+                mbs = jax.tree.map(resh, batch)
 
             def micro(carry, mb):
                 g_acc, loss_acc, acc_acc = carry

@@ -47,6 +47,7 @@ def init_rmsnorm(dim, dtype=jnp.float32):
     return {"scale": jnp.ones((dim,), dtype)}
 
 
+@jax.named_scope("norm")
 def rmsnorm(params, x, eps: float = 1e-6):
     dtype = x.dtype
     x = x.astype(jnp.float32)
@@ -59,6 +60,7 @@ def init_layernorm(dim, dtype=jnp.float32):
     return {"scale": jnp.ones((dim,), dtype), "bias": jnp.zeros((dim,), dtype)}
 
 
+@jax.named_scope("norm")
 def layernorm(params, x, eps: float = 1e-5):
     dtype = x.dtype
     x = x.astype(jnp.float32)
@@ -147,6 +149,7 @@ def _mask_bias(q_pos, k_pos, causal: bool, window: Optional[int]):
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
+@jax.named_scope("attn_core")
 def attention(q, k, v, *, causal=True, window=None, q_offset=0,
               kv_mask=None, softmax_scale=None):
     """Direct (materialized-scores) GQA attention.
@@ -170,6 +173,7 @@ def attention(q, k, v, *, causal=True, window=None, q_offset=0,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+@jax.named_scope("attn_core")
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                       kv_mask=None, q_chunk=1024, kv_chunk=1024,
                       softmax_scale=None):
@@ -275,6 +279,7 @@ def init_attention(key, cfg: AttnConfig, dtype=jnp.float32) -> Params:
     return p
 
 
+@jax.named_scope("attn_proj")
 def attention_qkv(params, x, cfg: AttnConfig, positions):
     """Project to grouped q, k, v and apply qk-norm + RoPE."""
     q = jnp.einsum("bsd,dkgh->bskgh", x, params["wq"])
@@ -395,6 +400,7 @@ def init_swiglu(key, d_model, d_ff, dtype=jnp.float32) -> Params:
     }
 
 
+@jax.named_scope("mlp")
 def apply_swiglu(params, x):
     g = jnp.einsum("bsd,df->bsf", x, params["w_gate"])
     u = jnp.einsum("bsd,df->bsf", x, params["w_up"])
@@ -412,6 +418,7 @@ def init_mlp(key, d_model, d_ff, act="gelu", dtype=jnp.float32) -> Params:
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params, x, act="gelu"):
     h = jnp.einsum("bsd,df->bsf", x, params["w_up"]) + params["b_up"]
     h = jax.nn.gelu(h) if act == "gelu" else jax.nn.relu(h)

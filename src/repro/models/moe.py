@@ -73,6 +73,7 @@ def _top_k_gating(logits, cfg: MoEConfig):
     return weights, idx, aux
 
 
+@jax.named_scope("moe")
 def apply_moe(params, x, cfg: MoEConfig, token_chunk: int = 8192):
     """x: (B, S, d) -> (B, S, d), aux_loss scalar.
 

@@ -100,6 +100,7 @@ def _causal_conv(params, x, conv_state=None):
     return (out + params["conv_b"].astype(jnp.float32)).astype(x.dtype), new_state
 
 
+@jax.named_scope("recurrence")
 def apply_recurrent(params, x, cfg: RecurrentConfig):
     """Full-sequence recurrent block. x: (B, S, d) -> (B, S, d)."""
     gate = jax.nn.gelu(jnp.einsum("bsd,dr->bsr", x, params["w_in_gate"]))
@@ -109,6 +110,7 @@ def apply_recurrent(params, x, cfg: RecurrentConfig):
     return jnp.einsum("bsr,rd->bsd", h * gate, params["w_out"])
 
 
+@jax.named_scope("recurrence")
 def apply_recurrent_decode(params, x, cfg: RecurrentConfig, state):
     """x: (B, 1, d); state: {"h": (B,r) f32, "conv": (B, W-1, r)}."""
     gate = jax.nn.gelu(jnp.einsum("bsd,dr->bsr", x, params["w_in_gate"]))

@@ -13,6 +13,14 @@ CPU host with 512 fake devices.
 
 ``vlm`` consumes precomputed patch embeddings (modality frontend is a stub per
 the assignment); ``audio`` lives in ``repro.models.encdec``.
+
+Every layer of the train step runs under one ``jax.named_scope`` (``embed``,
+``cast``, ``norm``, ``attn_proj``, ``attn_core``, ``mlp``, ``moe``,
+``recurrence``, ``layer_scan``, ``head``, ``xent``; ``grad_accum`` and
+``optimizer`` in ``launch.steps`` and ``optim``). The names reach each
+operation's metadata in the device trace, where the chip benchmark splits
+the step's time by them: keep them through refactors
+(``tests/test_named_scopes.py``). They change no compiled operation.
 """
 from __future__ import annotations
 
@@ -159,6 +167,7 @@ class SystemConfig:
 DEFAULT_SYS = SystemConfig()
 
 
+@jax.named_scope("cast")
 def _cast(params, dtype):
     return jax.tree.map(
         lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a,
@@ -208,14 +217,16 @@ def _apply_attn_block(p, x, cfg: ModelConfig, sys: SystemConfig, window=None,
         # whose score traffic the roofline's kernelized memory term
         # subtracts.)
         from repro.kernels import ops as kernel_ops
-        out = kernel_ops.flash_attention(
-            q, k, v, True, acfg.window, sys.q_chunk, sys.kv_chunk)
+        with jax.named_scope("attn_core"):
+            out = kernel_ops.flash_attention(
+                q, k, v, True, acfg.window, sys.q_chunk, sys.kv_chunk)
     elif S > 2048:
         out = layers.chunked_attention(q, k, v, causal=True, window=acfg.window,
                                        q_chunk=sys.q_chunk, kv_chunk=sys.kv_chunk)
     else:
         out = layers.attention(q, k, v, causal=True, window=acfg.window)
-    x = x + jnp.einsum("bskgh,kghd->bsd", out, p["attn"]["wo"])
+    with jax.named_scope("attn_proj"):
+        x = x + jnp.einsum("bskgh,kghd->bsd", out, p["attn"]["wo"])
     h = layers.rmsnorm(p["mlp_norm"], x)
     aux = jnp.float32(0)
     if "moe" in p:
@@ -349,11 +360,12 @@ def forward(params, batch, cfg: ModelConfig, sys: SystemConfig = DEFAULT_SYS,
     last_only projects the LM head on the final position only (prefill).
     """
     cparams = _cast(params, sys.compute_dtype)
-    if cfg.takes_embeddings:
-        x = batch["embeddings"].astype(sys.compute_dtype)
-        x = jnp.einsum("bsd,de->bse", x, cparams["adapter"])
-    else:
-        x = cparams["embed"][batch["tokens"]]
+    with jax.named_scope("embed"):
+        if cfg.takes_embeddings:
+            x = batch["embeddings"].astype(sys.compute_dtype)
+            x = jnp.einsum("bsd,de->bse", x, cparams["adapter"])
+        else:
+            x = cparams["embed"][batch["tokens"]]
 
     aux_total = jnp.float32(0)
     caches = None
@@ -364,7 +376,9 @@ def forward(params, batch, cfg: ModelConfig, sys: SystemConfig = DEFAULT_SYS,
                                               collect_cache=collect_cache,
                                               max_cache=max_cache)
             return x, (aux, cache) if collect_cache else (aux, 0)
-        x, (auxs, caches) = lax.scan(_remat(body, sys), x, cparams["layers"])
+        with jax.named_scope("layer_scan"):
+            x, (auxs, caches) = lax.scan(_remat(body, sys), x,
+                                         cparams["layers"])
         aux_total = auxs.sum()
     elif cfg.family == "hybrid":
         def body(x, lp):
@@ -377,12 +391,15 @@ def forward(params, batch, cfg: ModelConfig, sys: SystemConfig = DEFAULT_SYS,
                                               collect_cache=collect_cache,
                                               max_cache=max_cache)
             return x, (aux, cache) if collect_cache else (aux, 0)
-        x, (auxs, caches) = lax.scan(_remat(body, sys), x, cparams["layers"])
+        with jax.named_scope("layer_scan"):
+            x, (auxs, caches) = lax.scan(_remat(body, sys), x,
+                                         cparams["layers"])
         aux_total = auxs.sum()
         if cfg.hybrid_tail:
             def tail_body(x, rp):
                 return _apply_rec_block(rp, x, cfg), 0
-            x, _ = lax.scan(_remat(tail_body, sys), x, cparams["tail"])
+            with jax.named_scope("layer_scan"):
+                x, _ = lax.scan(_remat(tail_body, sys), x, cparams["tail"])
     elif cfg.family == "ssm":
         mcfg = cfg.mlstm_cfg()
 
@@ -395,24 +412,34 @@ def forward(params, batch, cfg: ModelConfig, sys: SystemConfig = DEFAULT_SYS,
             h = layers.rmsnorm(lp["slstm"]["norm"], x)
             out, _ = xlstm_lib.apply_slstm(lp["slstm"]["cell"], h, mcfg)
             return x + out, (jnp.float32(0), 0)
-        x, (auxs, _) = lax.scan(_remat(body, sys), x, cparams["layers"])
+        with jax.named_scope("layer_scan"):
+            x, (auxs, _) = lax.scan(_remat(body, sys), x, cparams["layers"])
         caches = None
 
     if last_only:
         x = x[:, -1:]
     x = layers.rmsnorm(params["final_norm"], x)
-    head = (cparams["embed"].T if cfg.tie_embeddings else cparams["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
+    logits = _logits(cparams, x, cfg)
     if collect_cache:
         return logits, aux_total, caches
     return logits, aux_total
 
 
+@jax.named_scope("head")
+def _logits(cparams, x, cfg: ModelConfig):
+    head = (cparams["embed"].T if cfg.tie_embeddings else cparams["lm_head"])
+    return jnp.einsum("bsd,dv->bsv", x, head,
+                      preferred_element_type=jnp.float32)
+
+
 def loss_fn(params, batch, cfg: ModelConfig, sys: SystemConfig = DEFAULT_SYS):
     logits, aux = forward(params, batch, cfg, sys)
-    labels = batch["labels"]
-    V = logits.shape[-1]
+    return _xent(logits, aux, batch["labels"])
+
+
+@jax.named_scope("xent")
+def _xent(logits, aux, labels):
+    """Mean next-token cross-entropy over labels >= 0, and the metrics."""
     lse = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     mask = (labels >= 0).astype(jnp.float32)
@@ -472,11 +499,10 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
     Returns (logits (B, 1, V), new_cache).
     """
     cparams = _cast(params, sys.compute_dtype)
-    if cfg.takes_embeddings:
+    with jax.named_scope("embed"):
         x = cparams["embed"][tokens]
-        x = jnp.einsum("bsd,de->bse", x, cparams["adapter"])
-    else:
-        x = cparams["embed"][tokens]
+        if cfg.takes_embeddings:
+            x = jnp.einsum("bsd,de->bse", x, cparams["adapter"])
 
     if cfg.family in ("dense", "moe", "vlm"):
         def body(x, xs):
@@ -528,7 +554,5 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
         raise ValueError(cfg.family)
 
     x = layers.rmsnorm(params["final_norm"], x)
-    head = (cparams["embed"].T if cfg.tie_embeddings else cparams["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
+    logits = _logits(cparams, x, cfg)
     return logits, new_cache

@@ -200,6 +200,7 @@ def _mlstm_qkv(params, u, cfg: MLSTMConfig):
     return q, k, v, gates[..., 0], gates[..., 1]
 
 
+@jax.named_scope("recurrence")
 def apply_mlstm(params, x, cfg: MLSTMConfig):
     """Full-sequence mLSTM block. x: (B, S, d)."""
     B, S, d = x.shape
@@ -214,6 +215,7 @@ def apply_mlstm(params, x, cfg: MLSTMConfig):
     return jnp.einsum("bsi,id->bsd", h * jax.nn.silu(gate), params["w_down"])
 
 
+@jax.named_scope("recurrence")
 def apply_mlstm_decode(params, x, cfg: MLSTMConfig, state):
     """x: (B,1,d); state {"C","n","m","conv"}."""
     u = jnp.einsum("bsd,di->bsi", x, params["w_up"])
@@ -267,6 +269,7 @@ def init_slstm(key, cfg: MLSTMConfig, dtype=jnp.float32):
     }
 
 
+@jax.named_scope("recurrence")
 def apply_slstm(params, x, cfg: MLSTMConfig, state=None):
     """Sequential sLSTM with exponential gating. x: (B, S, d)."""
     B, S, d = x.shape

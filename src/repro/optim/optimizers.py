@@ -64,6 +64,7 @@ def adamw(schedule, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.0,
     def init(params):
         return {"m": _zeros_like(params), "v": _zeros_like(params)}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params, step, lr_scale=1.0):
         if clip_norm is not None:
             grads, _ = clip_by_global_norm(grads, clip_norm)
@@ -95,6 +96,7 @@ def sgd(schedule, momentum=0.9, nesterov=False,
     def init(params):
         return {"mu": _zeros_like(params)}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params, step, lr_scale=1.0):
         if clip_norm is not None:
             grads, _ = clip_by_global_norm(grads, clip_norm)
@@ -113,5 +115,6 @@ def sgd(schedule, momentum=0.9, nesterov=False,
     return Optimizer(init, update)
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, updates):
     return jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)
