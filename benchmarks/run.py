@@ -222,7 +222,7 @@ def bench_kernels():
     q = jax.random.normal(ks[0], (1, 512, 4, 2, 64))
     k = jax.random.normal(ks[1], (1, 512, 4, 64))
     v = jax.random.normal(ks[2], (1, 512, 4, 64))
-    out = ops.flash_attention(q, k, v)
+    out = ops.flash_attention_fused(q, k, v)
     exp = ref.flash_attention_ref(q, k, v)
     err = float(jnp.max(jnp.abs(out - exp)))
     return f"fa_max_err={err:.1e}"

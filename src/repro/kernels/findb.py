@@ -5,8 +5,7 @@ problem shape and hardware — so production never re-tunes what the fleet
 already measured. This module is that table's process-local face for our
 Pallas kernels:
 
-- ``DEFAULTS`` holds the hand-picked fallback config per kernel (what the
-  call sites hard-coded before autotuning existed).
+- ``DEFAULTS`` holds the fallback config per kernel.
 - ``lookup_or_default(kernel, shape, default)`` is the fast path wired into
   ``flash_attention``/``mlstm``/``rglru`` and ``RealBackend``: a plain dict
   read against the active :class:`~repro.core.groundtruth.KernelConfigDB`.
@@ -36,11 +35,14 @@ __all__ = ["DEFAULTS", "attention_shape_key", "default_interpret",
            "mlstm_shape_key", "rglru_shape_key", "set_find_db",
            "shape_key", "train_step_shape_key"]
 
-# hand-picked defaults the call sites used before autotuning; the miss-path
-# answer of every lookup
+# the miss-path answer of every lookup. Flash blocks timed on a TPU v5e at
+# qwen3-0.6b's attention (K=8, G=2, D=128, bf16, causal; B=1, S=4096 and
+# B=8, S=1024): 1024 x 1024 is the fastest forward at both and the fastest
+# backward in the train step (``kernels.ops`` cuts them for wider rows).
+# The rest hand-picked.
 DEFAULTS = {
-    "flash_attention": {"q_block": 128, "kv_block": 128},
-    "flash_attention_bwd": {"q_block": 128, "kv_block": 128},
+    "flash_attention": {"q_block": 1024, "kv_block": 1024},
+    "flash_attention_bwd": {"q_block": 1024, "kv_block": 1024},
     "mlstm": {"chunk": 128},
     "rglru": {"chunk": 128, "r_block": 128},
     "train_step": {},

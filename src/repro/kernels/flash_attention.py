@@ -6,8 +6,10 @@ a TPU core iterates it sequentially, accumulating the online-softmax state
 (q_block x head_dim and kv_block x head_dim tiles, head_dim typically 128).
 
 Causal + sliding-window masks are applied per block; fully-masked kv blocks
-are skipped with pl.when (no MXU work issued). HBM traffic is q/k/v reads +
-one output write — the score matrices never leave VMEM, which is the entire
+are skipped with pl.when (no MXU work issued). The matmuls take the inputs'
+dtype (bf16 on the MXU in a bf16 model) with f32 accumulation; the softmax
+statistics and the accumulator stay f32. HBM traffic is q/k/v reads + one
+output write — the score matrices never leave VMEM, which is the entire
 point (FlashAttention adapted to the TPU memory hierarchy: HBM->VMEM DMA via
 BlockSpecs, MXU for the two matmuls, VPU for the softmax recurrence).
 """
@@ -56,9 +58,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs, scale: float,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                 # (Bq, D)
-        k = k_ref[0].astype(jnp.float32)                 # (Bk, D)
-        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[0]                                     # (Bq, D)
+        k = k_ref[0]                                     # (Bk, D)
+        v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -156,6 +158,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
             pltpu.VMEM((q_block, LANES), jnp.float32),   # running sum l
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q2, k2, v2)
     out = res[0] if return_lse else res
     out = jnp.moveaxis(out[:, :S].reshape(B, K, G, S, D), 3, 1)

@@ -10,7 +10,10 @@ Standard two-pass formulation (Dao, FlashAttention-2):
       accumulating dQ.
 
 The forward kernel is extended to also emit the per-row LSE so the backward
-can rebuild probabilities exactly.
+can rebuild probabilities exactly. As in the forward, the matmuls take the
+inputs' dtype with f32 accumulation: p and ds are cast to their partner's
+dtype before the MXU; lse, delta, the exponentials and the dq/dk/dv
+accumulators stay f32.
 """
 from __future__ import annotations
 
@@ -60,10 +63,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0, 0][:, None]                        # (Bq, 1)
         delta = delta_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -107,10 +107,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0, 0][:, None]                        # (Bq, 1)
         delta = delta_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -119,13 +116,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jnp.where(ok, s, NEG_INF)
         p = jnp.exp(s - lse)
         dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (Bk, D)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (Bk, D)
 
     @pl.when(qi == nq - 1)
@@ -199,6 +196,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         out_shape=jax.ShapeDtypeStruct((B * K * G, Sp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((q_block, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q2, k2, v2, do2, lse2, dl2)
     dq = jnp.moveaxis(dq2[:, :S].reshape(B, K, G, S, D), 3, 1)
 
@@ -232,6 +230,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         scratch_shapes=[pltpu.VMEM((kv_block, D), jnp.float32),
                         pltpu.VMEM((kv_block, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q2, k2, v2, do2, lse2, dl2)
     dk = jnp.moveaxis(dk2[:, :T].reshape(B, K, T, D), 2, 1)
     dv = jnp.moveaxis(dv2[:, :T].reshape(B, K, T, D), 2, 1)

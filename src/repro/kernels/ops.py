@@ -1,11 +1,12 @@
 """Public jit'd kernel ops with autodiff.
 
-Forward = Pallas kernel; backward = recompute through the jnp oracle
-(flash-style: nothing score-shaped is saved, the backward recomputes blocks).
+``flash_attention_fused``: Pallas forward and Pallas dq/dkv backward, what
+the train step runs on the TPU. ``rglru`` and ``mlstm`` pair a Pallas
+forward with a backward that recomputes through the jnp oracle.
 
 Block sizes and ``interpret`` default to None and resolve through the
 kernel find-db (``repro.kernels.findb``): tuned configs per (shape,
-hardware) when present, hand-picked fallbacks otherwise, and interpret
+hardware) when present, its fallbacks otherwise, and interpret
 auto-detected from the platform (compiled path on TPU, interpreted
 elsewhere). Resolution happens in the public wrappers *before* the
 ``custom_vjp`` boundary so the backward passes see concrete block sizes.
@@ -26,78 +27,53 @@ from repro.kernels import rglru as rglru_kernel
 from repro.kernels import ref
 
 
-def _resolve_attention(q, k, causal, window, q_block, kv_block, interpret):
+# the most bytes one block of q or kv rows may hold under the find-db's
+# fallback: its blocks were timed at 256-byte rows (D=128, bf16), and the
+# backward's 1024-row blocks overflow the 16 MiB scoped VMEM at 1024-byte
+# rows (D=256, fp32)
+FALLBACK_TILE_BYTES = 512 * 1024
+
+
+def _resolve_blocks(kernel, q, k, causal, window, q_block, kv_block):
+    """(q_block, kv_block) for ``kernel``: the find-db's entry for this
+    (shape, hardware), or its fallback cut to ``FALLBACK_TILE_BYTES``,
+    under the explicit arguments."""
     B, S, K, G, D = q.shape
-    if interpret is None:
-        interpret = findb.default_interpret()
     if q_block is None or kv_block is None:
+        rows = FALLBACK_TILE_BYTES // (D * q.dtype.itemsize)
         tuned = findb.lookup_or_default(
-            "flash_attention", findb.attention_shape_key(
+            kernel, findb.attention_shape_key(
                 B=B, S=S, K=K, G=G, D=D, T=k.shape[1],
-                causal=causal, window=window))
+                causal=causal, window=window),
+            {name: min(b, rows) for name, b in findb.DEFAULTS[kernel].items()})
         q_block = tuned["q_block"] if q_block is None else q_block
         kv_block = tuned["kv_block"] if kv_block is None else kv_block
-    return int(q_block), int(kv_block), bool(interpret)
+    return int(q_block), int(kv_block)
 
 
 # ---------------------------------------------------------------- attention
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, causal, window, q_block, kv_block, interpret):
-    return fa_kernel.flash_attention(q, k, v, causal=causal, window=window,
-                                     q_block=q_block, kv_block=kv_block,
-                                     interpret=interpret)
-
-
-def _fa_fwd(q, k, v, causal, window, q_block, kv_block, interpret):
-    out = _flash_attention(q, k, v, causal, window, q_block, kv_block,
-                           interpret)
-    return out, (q, k, v)
-
-
-def _fa_bwd(causal, window, q_block, kv_block, interpret, res, g):
-    q, k, v = res
-    # recompute-through-oracle backward (identical math, nothing saved)
-    _, vjp = jax.vjp(lambda q, k, v: ref.flash_attention_ref(
-        q, k, v, causal=causal, window=window,
-        q_chunk=q_block, kv_chunk=kv_block), q, k, v)
-    return vjp(g)
-
-
-_flash_attention.defvjp(_fa_fwd, _fa_bwd)
-
-
-def flash_attention(q, k, v, causal=True, window=None, q_block=None,
-                    kv_block=None, interpret=None):
-    q_block, kv_block, interpret = _resolve_attention(
-        q, k, causal, window, q_block, kv_block, interpret)
-    return _flash_attention(q, k, v, causal, window, q_block, kv_block,
-                            interpret)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention_fused(q, k, v, causal, window, q_block, kv_block,
+def _flash_attention_fused(q, k, v, causal, window, fwd_blocks, bwd_blocks,
                            interpret):
     """Kernel forward AND kernel backward (dq/dk/dv Pallas kernels) —
     score blocks never touch HBM in either direction."""
-    out, _ = fa_kernel.flash_attention(
-        q, k, v, causal=causal, window=window, q_block=q_block,
-        kv_block=kv_block, interpret=interpret, return_lse=True)
-    return out
+    return _faf_fwd(q, k, v, causal, window, fwd_blocks, bwd_blocks,
+                    interpret)[0]
 
 
-def _faf_fwd(q, k, v, causal, window, q_block, kv_block, interpret):
+def _faf_fwd(q, k, v, causal, window, fwd_blocks, bwd_blocks, interpret):
     out, lse = fa_kernel.flash_attention(
-        q, k, v, causal=causal, window=window, q_block=q_block,
-        kv_block=kv_block, interpret=interpret, return_lse=True)
+        q, k, v, causal=causal, window=window, q_block=fwd_blocks[0],
+        kv_block=fwd_blocks[1], interpret=interpret, return_lse=True)
     return out, (q, k, v, out, lse)
 
 
-def _faf_bwd(causal, window, q_block, kv_block, interpret, res, g):
+def _faf_bwd(causal, window, fwd_blocks, bwd_blocks, interpret, res, g):
     q, k, v, out, lse = res
     return fa_bwd_kernel.flash_attention_bwd(
         q, k, v, out, lse, g, causal=causal, window=window,
-        q_block=q_block, kv_block=kv_block, interpret=interpret)
+        q_block=bwd_blocks[0], kv_block=bwd_blocks[1], interpret=interpret)
 
 
 _flash_attention_fused.defvjp(_faf_fwd, _faf_bwd)
@@ -105,10 +81,16 @@ _flash_attention_fused.defvjp(_faf_fwd, _faf_bwd)
 
 def flash_attention_fused(q, k, v, causal=True, window=None, q_block=None,
                           kv_block=None, interpret=None):
-    q_block, kv_block, interpret = _resolve_attention(
-        q, k, causal, window, q_block, kv_block, interpret)
-    return _flash_attention_fused(q, k, v, causal, window, q_block,
-                                  kv_block, interpret)
+    """The forward's blocks resolve under ``flash_attention``, the two
+    backward kernels' under ``flash_attention_bwd``; explicit blocks set
+    both."""
+    blocks = [_resolve_blocks(kernel, q, k, causal, window, q_block,
+                              kv_block)
+              for kernel in ("flash_attention", "flash_attention_bwd")]
+    if interpret is None:
+        interpret = findb.default_interpret()
+    return _flash_attention_fused(q, k, v, causal, window, *blocks,
+                                  bool(interpret))
 
 
 # ------------------------------------------------------------------- rglru

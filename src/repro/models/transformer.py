@@ -152,7 +152,11 @@ class SystemConfig:
     batch_axes: tuple = ()              # mesh axes carrying the batch dim
     q_chunk: int = 1024
     kv_chunk: int = 1024
-    use_pallas: bool = False            # TPU runtime only; CPU dry-run = False
+    # attention on the fused Pallas flash kernels. None: where compiled
+    # Pallas exists (TPU) and the step runs on one chip (a Mosaic kernel
+    # is not partitioned across a mesh); elsewhere the jnp path, sized by
+    # q_chunk/kv_chunk
+    use_pallas: Optional[bool] = None
     kv_quant: bool = False              # int8 KV cache (decode memory term /2)
 
     @property
@@ -183,6 +187,13 @@ def _remat(fn, sys: SystemConfig):
     return jax.checkpoint(fn)           # "block": save block boundaries only
 
 
+def _use_pallas(sys: SystemConfig) -> bool:
+    if sys.use_pallas is None:
+        from repro.kernels import findb
+        return sys.chips == 1 and not findb.default_interpret()
+    return sys.use_pallas
+
+
 # ---------------------------------------------------------------------------
 # per-layer blocks
 # ---------------------------------------------------------------------------
@@ -210,16 +221,14 @@ def _apply_attn_block(p, x, cfg: ModelConfig, sys: SystemConfig, window=None,
     q = layers.shard_heads(q, sys.shard_attn)
     k = layers.shard_heads(k, sys.shard_attn)
     v = layers.shard_heads(v, sys.shard_attn)
-    if sys.use_pallas:
-        # TPU runtime path: the flash kernel keeps score blocks in VMEM.
-        # (interpret resolves through findb.default_interpret(): compiled
-        # on TPU, interpreted elsewhere; the dry-run keeps the jnp path,
-        # whose score traffic the roofline's kernelized memory term
-        # subtracts.)
+    if _use_pallas(sys):
+        # the fused flash kernels keep score blocks in VMEM in both
+        # directions; their blocks resolve through the kernel find-db
+        # (the dry-run keeps the jnp path, whose score traffic the
+        # roofline's kernelized memory term subtracts)
         from repro.kernels import ops as kernel_ops
         with jax.named_scope("attn_core"):
-            out = kernel_ops.flash_attention(
-                q, k, v, True, acfg.window, sys.q_chunk, sys.kv_chunk)
+            out = kernel_ops.flash_attention_fused(q, k, v, True, acfg.window)
     elif S > 2048:
         out = layers.chunked_attention(q, k, v, causal=True, window=acfg.window,
                                        q_chunk=sys.q_chunk, kv_chunk=sys.kv_chunk)

@@ -11,6 +11,7 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention as fa_raw
 from repro.kernels.mlstm import mlstm_chunkwise as mlstm_raw
 from repro.kernels.rglru import rglru_scan as rglru_raw
+from repro.models import layers
 
 
 def _tol(dtype):
@@ -60,7 +61,7 @@ def test_flash_attention_grad_matches_ref():
     q = jax.random.normal(ks[0], (1, 128, 2, 2, 64))
     k = jax.random.normal(ks[1], (1, 128, 2, 64))
     v = jax.random.normal(ks[2], (1, 128, 2, 64))
-    g1 = jax.grad(lambda q, k, v: ops.flash_attention(q, k, v).sum(),
+    g1 = jax.grad(lambda q, k, v: ops.flash_attention_fused(q, k, v).sum(),
                   argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(lambda q, k, v: ref.attention_direct_ref(q, k, v).sum(),
                   argnums=(0, 1, 2))(q, k, v)
@@ -163,6 +164,53 @@ def test_flash_attention_fused_bwd(B, S, K, G, D, causal, window):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-4)
+
+
+BF16_TOL = 2e-2       # max |x - ref| / max |ref|, as chip_smoke.py holds
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+@pytest.mark.parametrize("B,S,window", [
+    (1, 256, None),
+    (1, 256, 72),                       # sliding window
+    (2, 200, None),                     # ragged vs the blocks
+])
+def test_flash_attention_fused_bf16(B, S, window):
+    """bf16 operands on the MXU (qwen3-0.6b's head layout, scaled down:
+    K=2 kv heads, G=2, D=128, causal): the fused forward and backward stay
+    within bf16 rounding of the f32 oracle, and of the chunked jnp path
+    they replace run on the same bf16 inputs."""
+    K, G, D = 2, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(ks[0], (B, S, K, G, D)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, K, D)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, K, D)).astype(jnp.bfloat16)
+    co = jax.random.normal(ks[3], (B, S, K, G, D))
+
+    def grads(attn, *args):
+        def loss(q, k, v):
+            out = attn(q, k, v)
+            return (out.astype(jnp.float32) * co).sum(), out
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(*args)
+
+    fused = grads(lambda q, k, v: ops.flash_attention_fused(
+        q, k, v, True, window, 64, 64), q, k, v)
+    chunked = grads(lambda q, k, v: layers.chunked_attention(
+        q, k, v, causal=True, window=window, q_chunk=64, kv_chunk=64),
+        q, k, v)
+    with jax.default_matmul_precision("highest"):
+        oracle = grads(lambda q, k, v: ref.attention_direct_ref(
+            q, k, v, causal=True, window=window),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    assert fused[1].dtype == jnp.bfloat16
+    for got, want in zip(jax.tree.leaves(fused), jax.tree.leaves(oracle)):
+        assert _rel_err(got, want) <= BF16_TOL
+    for got, want in zip(jax.tree.leaves(fused), jax.tree.leaves(chunked)):
+        assert _rel_err(got, want) <= BF16_TOL
 
 
 def test_flash_attention_lse_matches_ref():

@@ -5,7 +5,9 @@ layout rules are checked here, where the interpreter checks none.
 The topology is described inside a fixture only: one process at a time may
 load the TPU library, and every test worker imports this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,12 +69,30 @@ def _flash_bwd(q, k, v):
         argnums=(0, 1, 2))(q, k, v)
 
 
+def _local_fp32_args():
+    # recurrentgemma-9b's local attention in fp32: the widest rows (D=256,
+    # 4 bytes) that the find-db's fallback blocks must fit in VMEM
+    cfg = configs.get_config("recurrentgemma-9b")
+    G, D = cfg.n_heads // cfg.n_kv_heads, cfg.resolved_head_dim
+    return [((1, 4096, cfg.n_kv_heads, G, D), jnp.float32),
+            ((1, 4096, cfg.n_kv_heads, D), jnp.float32),
+            ((1, 4096, cfg.n_kv_heads, D), jnp.float32)]
+
+
+def _flash_local_bwd(q, k, v):
+    window = configs.get_config("recurrentgemma-9b").window
+    return jax.grad(lambda q, k, v: ops.flash_attention_fused(
+        q, k, v, True, window, interpret=False).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 # name -> (function, argument shapes, Pallas calls the program must hold)
 CASES = {
-    "flash_fwd": (lambda q, k, v: ops.flash_attention(q, k, v,
-                                                      interpret=False),
+    "flash_fwd": (lambda q, k, v: ops.flash_attention_fused(
+        q, k, v, interpret=False),
                   _flash_args, 1),
     "flash_fwd_bwd": (_flash_bwd, _flash_args, 3),
+    "flash_local_fp32_fwd_bwd": (_flash_local_bwd, _local_fp32_args, 3),
     "mlstm": (lambda *a: ops.mlstm(*a, interpret=False), _mlstm_args, 1),
     "rglru": (lambda *a: ops.rglru(*a, interpret=False), _rglru_args, 1),
 }
@@ -86,3 +106,33 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*sds).compile()
     assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
         >= n_kernels
+
+
+def test_train_step_compiles_with_flash_kernels_for_v5e(one_chip,
+                                                        monkeypatch):
+    """qwen3-0.6b's train step at its widths (two layers), B=1, S=4096,
+    bf16, remat=block, on the fused flash kernels at the find-db's
+    fallback blocks: the forward, dq and dkv kernels compile within
+    Mosaic's tile and VMEM limits and sit under the ``attn_core`` scope."""
+    from repro.kernels import findb
+    from repro.launch import steps
+    from repro.models.transformer import SystemConfig
+    from repro.optim import optimizers
+    monkeypatch.setattr(findb, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(configs.get_config("qwen3-0.6b"), n_layers=2)
+    opt = optimizers.adamw(1e-3)
+    step = steps.make_train_step(
+        cfg, SystemConfig(remat="block", precision="bf16", use_pallas=True),
+        opt)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        steps.abstract_state(cfg, opt))
+    batch = {k: jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    hlo = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile() \
+        .as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= 3
+    for line in calls:
+        assert "attn_core" in re.search(r'op_name="([^"]*)"', line).group(1)
